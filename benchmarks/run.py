@@ -30,6 +30,9 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     out_dir: Path = args.out
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import tables as T
     from benchmarks import kernel_perf as K
     from benchmarks import lm_perf as LMP

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer_lm as TLM
 from repro.quant.matmul import list_backends
 from repro.quant.quantize import for_lm
@@ -62,6 +63,7 @@ ap.add_argument("--mesh", default=None, metavar="AXES",
                      "(launch/mesh.py picks the factorization); served "
                      "tokens are identical to the single-device engine")
 args = ap.parse_args()
+enable_compile_cache()
 
 cfg = registry.reduced("smollm-135m", n_layers=4, d_model=128, d_ff=256)
 cfg = dataclasses.replace(cfg, quant=for_lm(args.backend))

@@ -26,6 +26,11 @@ sweep-level difference (e.g. a --full baseline's 2048 rows vs a quick CI
 run) and is reported informationally. ``--update`` re-baselines: it
 copies the fresh results over the committed files (bench_results.json
 plus any versioned artifacts the run produced).
+
+This script runs the benchmark as a child process and never imports JAX
+itself: on a machine with an accelerator the chip belongs to one process,
+and a parent that had touched JAX would hold it while the child waited.
+Keep it that way.
 """
 from __future__ import annotations
 

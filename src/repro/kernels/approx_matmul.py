@@ -6,11 +6,12 @@ kernels:
 1. deficit — bit-exact emulation of the paper's multiplier. Per (bm, bn, bk)
    tile: the exact int8 dot runs on the MXU; the error term is accumulated
    by a fori_loop over k-chunks of width ``kv`` evaluating the *deficit
-   planes* (core/deficit.py) on (bm, kv, bn) broadcasts — pure VPU bit-ops,
+   planes* (core/deficit.py) on (kv, bm, bn) broadcasts — pure VPU bit-ops,
    no gathers, no 64K LUT in VMEM. This is the TPU-native port of the
    circuit: the same boolean sites, evaluated as vector ops. ``kv`` trades
-   loop trips for intermediate size (bm * kv * bn i32 planes); kv=1
-   reproduces the original one-column-at-a-time loop.
+   loop trips for intermediate size (kv * bm * bn i32 planes, capped at
+   ``_PLANE_BYTES``); kv=1 reproduces the original one-column-at-a-time
+   loop.
 
 2. stage1 — the beyond-paper re-approximation: exact tile dot minus the 7
    rank-1 stage-1 site corrections, each itself a tile dot (all MXU work,
@@ -41,9 +42,12 @@ Entry points:
                            is a grid axis: (B, T, K) activations hit the
                            kernel without host-side reshape/copy.
 
-Block sizes default to MXU-aligned (128, 128, 128); VMEM budget per tile:
-x (bm,bk) + w (bk,bn) int8 + out (bm,bn) i32/f32 + acc scratch + kv deficit
-planes (bm,kv,bn) i32 ≈ 0.1 MB + kv * 64K — within ~16 MB/core for kv<=32.
+Block sizes default to MXU-aligned (128, 128, 128). Every MXU dot takes
+int8 operands with an int32 result (Mosaic refuses int32 x int32); int32
+is for the VPU bit-plane work only. The deficit body's live planes are
+what bounds VMEM: ``_PLANE_BYTES`` keeps them inside the compiler's
+default 16 MiB scoped limit at every tile size
+(tests/test_tpu_compile.py compiles each kernel for a v5e).
 """
 from __future__ import annotations
 
@@ -68,27 +72,44 @@ def _exact_dot(x, w):
 # Shared tile bodies
 # ---------------------------------------------------------------------------
 
-def _deficit_tile_err(x, w, design: str, kv: int):
+# Bytes of one (kv, bm, bn) int32 deficit plane. The tile body keeps a few
+# dozen such planes live, so this bounds the kernel's VMEM stack: at 512 KiB
+# a (128, 128, 128) tile evaluates 8 k-columns per trip and fits the
+# compiler's default 16 MiB scoped-VMEM limit.
+_PLANE_BYTES = 512 * 1024
+
+
+def _plane_kv(kv: int, bk: int, bm: int, bn: int) -> int:
+    """Largest divisor of bk, not above kv, whose deficit planes fit
+    ``_PLANE_BYTES``."""
+    kv = max(1, min(kv, bk, _PLANE_BYTES // (4 * bm * bn)))
+    while bk % kv:
+        kv -= 1
+    return kv
+
+
+def _deficit_tile_err(xt_ref, w_ref, design: str, kv: int):
     """sum_k deficit(|x[m,k]|, |w[k,n]|) * sign for one (bm, bk, bn) tile.
 
-    Evaluates the deficit planes on (bm, kv, bn) broadcasts, kv k-columns
-    per loop trip. Integer-exact for any kv; padded k-columns contribute
-    zero because their sign product is zero.
+    ``xt_ref`` holds the x tile transposed, (bk, bm), so that a k-chunk of
+    either operand is a sublane slice of its ref (`pl.ds` reads; Mosaic has
+    no dynamic lane slice). The deficit planes are evaluated on
+    (kv, bm, bn) broadcasts, kv k-rows per loop trip, and summed over the
+    leading axis. Integer-exact for any kv; padded k-rows contribute zero
+    because their sign product is zero.
     """
-    bm, bk = x.shape
-    bn = w.shape[1]
-    while bk % kv:          # largest divisor of bk not above the requested kv
-        kv -= 1
-    xmag, wmag = jnp.abs(x), jnp.abs(w)
-    xsgn, wsgn = jnp.sign(x), jnp.sign(w)
+    bk, bm = xt_ref.shape
+    bn = w_ref.shape[1]
+    kv = _plane_kv(kv, bk, bm, bn)
 
     def body(c, err):
-        a = jax.lax.dynamic_slice_in_dim(xmag, c * kv, kv, axis=1)   # (bm,kv)
-        sa = jax.lax.dynamic_slice_in_dim(xsgn, c * kv, kv, axis=1)
-        b = jax.lax.dynamic_slice_in_dim(wmag, c * kv, kv, axis=0)   # (kv,bn)
-        sb = jax.lax.dynamic_slice_in_dim(wsgn, c * kv, kv, axis=0)
-        df = D.deficit_sum(a[:, :, None], b[None, :, :], design)
-        return err + (df * (sa[:, :, None] * sb[None, :, :])).sum(axis=1)
+        off = pl.multiple_of(c * kv, kv)
+        a = xt_ref[pl.ds(off, kv), :].astype(jnp.int32)      # (kv, bm)
+        b = w_ref[pl.ds(off, kv), :].astype(jnp.int32)       # (kv, bn)
+        df = D.deficit_sum(jnp.abs(a)[:, :, None], jnp.abs(b)[:, None, :],
+                           design)                           # (kv, bm, bn)
+        sgn = jnp.sign(a)[:, :, None] * jnp.sign(b)[:, None, :]
+        return err + (df * sgn).sum(axis=0)
 
     return jax.lax.fori_loop(0, bk // kv, body,
                              jnp.zeros((bm, bn), jnp.int32))
@@ -96,10 +117,11 @@ def _deficit_tile_err(x, w, design: str, kv: int):
 
 def _stage1_tile_corr(x, w):
     """sum of the 7 rank-1 stage-1 site corrections for one tile (each an
-    MXU dot over {-1,0,1} window features)."""
-
-    xmag, wmag = jnp.abs(x), jnp.abs(w)
-    xsgn, wsgn = jnp.sign(x), jnp.sign(w)
+    MXU int8 dot over {-1,0,1} window features; the bit windows are VPU
+    int32 work)."""
+    xi, wi = x.astype(jnp.int32), w.astype(jnp.int32)
+    xmag, wmag = jnp.abs(xi), jnp.abs(wi)
+    xsgn, wsgn = jnp.sign(xi), jnp.sign(wi)
 
     def window(v, s):
         out = (v >> s) & 1
@@ -109,8 +131,8 @@ def _stage1_tile_corr(x, w):
 
     corr = None
     for col, ra, rb in STAGE1_SITES:
-        u = window(xmag, ra) * xsgn            # (bm, bk) in {-1,0,1}
-        v = window(wmag, rb) * wsgn
+        u = (window(xmag, ra) * xsgn).astype(jnp.int8)   # (bm, bk)
+        v = (window(wmag, rb) * wsgn).astype(jnp.int8)   # (bk, bn)
         term = _exact_dot(u, v) << col
         corr = term if corr is None else corr + term
     return corr
@@ -120,16 +142,15 @@ def _stage1_tile_corr(x, w):
 # int32 kernels (pre-dequant contract, 2D)
 # ---------------------------------------------------------------------------
 
-def _approx_kernel(x_ref, w_ref, o_ref, *, design: str, kv: int):
+def _approx_kernel(x_ref, xt_ref, w_ref, o_ref, *, design: str, kv: int):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.int32)           # (bm, bk)
-    w = w_ref[...].astype(jnp.int32)           # (bk, bn)
-    o_ref[...] += _exact_dot(x, w) - _deficit_tile_err(x, w, design, kv)
+    o_ref[...] += (_exact_dot(x_ref[...], w_ref[...])
+                   - _deficit_tile_err(xt_ref, w_ref, design, kv))
 
 
 def _stage1_kernel(x_ref, w_ref, o_ref):
@@ -139,8 +160,7 @@ def _stage1_kernel(x_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
+    x, w = x_ref[...], w_ref[...]
     o_ref[...] += _exact_dot(x, w) - _stage1_tile_corr(x, w)
 
 
@@ -175,19 +195,23 @@ def _rank1_kernel(*refs, nd: int):
 # fused-epilogue kernel (batched, float32 out)
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref, *,
-                  nk: int, design: str, variant: str, relu: bool, kv: int):
+def _fused_kernel(*refs, nk: int, design: str, variant: str, relu: bool,
+                  kv: int):
+    if variant == "deficit":   # only the deficit body reads the x^T tile
+        x_ref, xt_ref, w_ref, s_ref, b_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref = refs
     k_idx = pl.program_id(3)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.int32)             # (bm, bk)
-    w = w_ref[...].astype(jnp.int32)           # (bk, bn)
+    x = x_ref[...]                             # (bm, bk) int8
+    w = w_ref[...]                             # (bk, bn) int8
     acc = _exact_dot(x, w)
     if variant == "deficit":
-        acc = acc - _deficit_tile_err(x, w, design, kv)
+        acc = acc - _deficit_tile_err(xt_ref, w_ref, design, kv)
     elif variant == "stage1":
         acc = acc - _stage1_tile_corr(x, w)
     # variant == "exact": plain int8 dot
@@ -198,7 +222,7 @@ def _fused_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref, *,
         out = acc_ref[...].astype(jnp.float32) * s_ref[...] + b_ref[...]
         if relu:
             out = jnp.maximum(out, 0.0)
-        o_ref[0] = out
+        o_ref[...] = out
 
 
 def _rank1_fused_kernel(*refs, nk: int, nd: int, relu: bool):
@@ -260,18 +284,24 @@ def approx_matmul_pallas(x_q: jax.Array, w_q: jax.Array,
     np_ = wp.shape[1]
     grid = (mp // bm, np_ // bn, kp // bk)
 
-    body = (functools.partial(_approx_kernel, design=design, kv=kv)
-            if kernel == "deficit" else _stage1_kernel)
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
+    w_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
+    if kernel == "deficit":
+        body = functools.partial(_approx_kernel, design=design, kv=kv)
+        in_specs = [x_spec, pl.BlockSpec((bk, bm), lambda i, j, kk: (kk, i)),
+                    w_spec]
+        operands = (xp, xp.T, wp)
+    else:
+        body, in_specs, operands = _stage1_kernel, [x_spec, w_spec], (xp, wp)
     out = pl.pallas_call(
         body,
         grid=grid,
-        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-                  pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         interpret=interpret,
         **_compiler_params(interpret, 2),
-    )(xp, wp)
+    )(*operands)
     return out[:m, :n]
 
 
@@ -314,20 +344,27 @@ def fused_matmul_pallas(x_q: jax.Array, w_q: jax.Array,
     bp = _pad_to(bias.astype(jnp.float32), (bn,), (1,))
     grid = (batch, mp // bm, np_ // bn, kp // bk)
 
+    x_specs = [pl.BlockSpec((None, bm, bk), lambda b, i, j, kk: (b, i, kk))]
+    x_ops = [xp]
+    if variant == "deficit":
+        x_specs.append(pl.BlockSpec((None, bk, bm),
+                                    lambda b, i, j, kk: (b, kk, i)))
+        x_ops.append(jnp.swapaxes(xp, 1, 2))
     out = pl.pallas_call(
         functools.partial(_fused_kernel, nk=kp // bk, design=design,
                           variant=variant, relu=relu, kv=kv),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, bm, bk), lambda b, i, j, kk: (b, i, kk)),
-                  pl.BlockSpec((bk, bn), lambda b, i, j, kk: (kk, j)),
-                  pl.BlockSpec((1, bn), lambda b, i, j, kk: (0, j)),
-                  pl.BlockSpec((1, bn), lambda b, i, j, kk: (0, j))],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, kk: (b, i, j)),
+        in_specs=x_specs
+                 + [pl.BlockSpec((bk, bn), lambda b, i, j, kk: (kk, j)),
+                    pl.BlockSpec((1, bn), lambda b, i, j, kk: (0, j)),
+                    pl.BlockSpec((1, bn), lambda b, i, j, kk: (0, j))],
+        out_specs=pl.BlockSpec((None, bm, bn),
+                               lambda b, i, j, kk: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((batch, mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         **_compiler_params(interpret, 3),
-    )(xp, wp, sp, bp)
+    )(*x_ops, wp, sp, bp)
     out = out[:, :m, :n]
     return out[0] if squeeze else out
 

@@ -33,7 +33,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.launch import specs as SP
 from repro.models import transformer_lm as TLM
 from repro.optim import adamw
-from repro.parallel.sharding import DEFAULT_RULES, use_mesh
+from repro.parallel.sharding import DEFAULT_RULES
 from repro.train import steps as ST
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -58,7 +58,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, quant: str = "bf16",
         # big-model default: bound remat-residual memory (DESIGN.md §5)
         microbatches = 8
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         inputs = SP.input_specs(cfg, shape, mesh, rules)
         if kind == "train":
             opt_cfg = adamw.AdamWConfig(quantized_state=True)

@@ -249,12 +249,5 @@ class HloCost:
 
 
 def builtin_cost_analysis(compiled) -> Dict:
-    """XLA's own cost analysis as a flat dict, across jax versions.
-
-    jax <= 0.4.x returns a one-element list of per-module dicts from
-    `compiled.cost_analysis()`; newer versions return the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """XLA's own cost analysis of a compiled program, as a flat dict."""
+    return dict(compiled.cost_analysis())

@@ -17,7 +17,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(fn: Callable, mesh: Mesh, params, microbatches,
@@ -57,6 +56,6 @@ def pipeline_apply(fn: Callable, mesh: Mesh, params, microbatches,
             jnp.where(sid == n_stages - 1, outs, 0), stage_axis)
         return outs
 
-    f = shard_map(pipelined, mesh=mesh, in_specs=in_specs, out_specs=PS(),
-                  check_rep=False)
+    f = jax.shard_map(pipelined, mesh=mesh, in_specs=in_specs,
+                      out_specs=PS(), check_vma=False)
     return f(params, microbatches)

@@ -19,20 +19,10 @@ import dataclasses
 from typing import Dict, Optional, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+from jax.sharding import (AbstractMesh, Mesh, NamedSharding,
+                          PartitionSpec as PS)
 
 Axes = Union[None, str, Tuple[str, ...]]
-
-
-def use_mesh(mesh: Mesh):
-    """Version-portable `jax.set_mesh`: a context manager installing `mesh`
-    as the ambient mesh. jax >= 0.6 has jax.set_mesh; 0.5.x has
-    jax.sharding.use_mesh; on 0.4.x Mesh itself is the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,16 +138,13 @@ def mesh_axis_size(axis: str) -> int:
     m = _current_mesh()
     if m is None or axis not in m.axis_names:
         return 1
-    return dict(zip(m.axis_names, m.devices.shape))[axis]
+    return m.shape[axis]
 
 
-def _current_mesh() -> Optional[Mesh]:
-    try:  # jax.set_mesh context (jax >= 0.5 style)
-        m = jax._src.mesh.get_concrete_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    env = jax._src.mesh.thread_resources.env  # legacy `with mesh:` context
-    m = env.physical_mesh
-    return m if m and not m.empty else None
+def _current_mesh() -> Optional[AbstractMesh]:
+    """The ambient mesh installed by ``jax.set_mesh``, or None.
+
+    Inside a shard_map body the ambient mesh has manual axes: the body is
+    per-device code and takes no sharding constraints, so it sees none."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty or m.manual_axes else m

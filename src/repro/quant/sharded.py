@@ -26,8 +26,8 @@ tests/test_sharded_backends.py):
                  max-reductions — order-invariant — so quantize outside
                  the shard_map is bitwise regardless of operand sharding.
 
-The Pallas backends run under shard_map with ``check_rep=False`` (pallas
-calls define no replication rule); correctness is carried by the specs.
+The Pallas backends run under shard_map with ``check_vma=False`` (pallas
+calls define no varying-axes rule); correctness is carried by the specs.
 """
 from __future__ import annotations
 
@@ -36,7 +36,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as PS
 
 from repro.quant.matmul import (_pin, _resolve_backend,  # noqa: F401
@@ -98,9 +97,9 @@ def sharded_integer_matmul(x_q: jax.Array, w_q: jax.Array, cfg: QuantConfig,
             part = jax.lax.psum(part, k_ax)   # int32: exact in any order
         return part
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(PS(m_ax, k_ax), PS(k_ax, n_ax)),
-                   out_specs=PS(m_ax, n_ax), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(PS(m_ax, k_ax), PS(k_ax, n_ax)),
+                       out_specs=PS(m_ax, n_ax), check_vma=False)
     return fn(x_q, w_q)
 
 

@@ -46,7 +46,6 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from repro.models import transformer_lm as TLM
@@ -146,7 +145,7 @@ def clear_compiled_fns() -> None:
 # reassociates float contractions across shards (K-dim FSDP sums, fused
 # gemm tiling), which breaks bitwise parity. This formulation keeps every
 # float op local and unchanged; the only cross-device ops are exact byte
-# movement. check_rep=False because Pallas backends define no replication
+# movement. check_vma=False because Pallas backends define no varying-axes
 # rule.
 
 
@@ -255,14 +254,14 @@ def mesh_compiled_fns(cfg: ArchConfig, rules: ShardingRules, mesh: Mesh,
         return logits, [_slice_leaf(x, s, sizes, skip_dim=1)
                         for x, s in zip(jax.tree.leaves(new), pool_specs)]
 
-    sm_prefill = shard_map(
+    sm_prefill = jax.shard_map(
         prefill_body, mesh=mesh,
         in_specs=(pspecs, one_specs, PS(None, None), PS(None), PS()),
-        out_specs=(PS(None, None, None), one_specs), check_rep=False)
-    sm_decode = shard_map(
+        out_specs=(PS(None, None, None), one_specs), check_vma=False)
+    sm_decode = jax.shard_map(
         decode_body, mesh=mesh,
         in_specs=(pspecs, pool_specs, PS(slot_ax, None), PS(slot_ax)),
-        out_specs=(PS(slot_ax, None, None), pool_specs), check_rep=False)
+        out_specs=(PS(slot_ax, None, None), pool_specs), check_vma=False)
 
     def prefill(p, toks, cache, lengths, off):
         logits, nf = sm_prefill(jax.tree.leaves(p), jax.tree.leaves(cache),
@@ -301,12 +300,17 @@ class Engine:
                  rules: ShardingRules = DEFAULT_RULES,
                  admission: str = "continuous",
                  stream: Optional[Callable[[int, int], None]] = None,
-                 cache_dtype=jnp.float32,
+                 cache_dtype=None,
                  prefix_caching: bool = True, page_size: int = 8,
                  cache_pages: Optional[int] = None,
                  mesh: Optional[Mesh] = None,
                  spec=None, draft_params=None):
         assert not cfg.embed_stub, "serving drives token models"
+        # the KV cache holds what the layers compute, in the params' dtype
+        # unless asked otherwise: a bf16 model's scan carry is bf16, and
+        # attention returns the cache dtype
+        if cache_dtype is None:
+            cache_dtype = cfg.param_dtype
         self.cfg, self.params, self.rules = cfg, params, rules
         self.slots, self.max_len, self.eos_id = slots, max_len, eos_id
         self.stream = stream

@@ -14,7 +14,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS
 from repro.configs import registry
 from repro.models import transformer_lm as TLM
 from repro.nn import module as M
-from repro.parallel.sharding import (DEFAULT_RULES, prune_spec, use_mesh)
+from repro.parallel.sharding import DEFAULT_RULES, prune_spec
 
 
 def test_sharded_loss_matches_single_device():
@@ -34,7 +34,7 @@ def test_sharded_loss_matches_single_device():
 
     # sharded 4x2 mesh
     mesh = jax.make_mesh((4, 2), ("data", "model"))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         specs = M.param_shardings(TLM.descs(cfg), DEFAULT_RULES, mesh)
         p_sh = jax.tree.map(
             lambda x, sp: jax.device_put(

@@ -54,15 +54,10 @@ def test_kernel_zero_and_identity_operands():
     np.testing.assert_array_equal(np.asarray(out), 3)  # 3*1 exact (tiny pp)
 
 
-def test_kernel_lowers_for_tpu():
-    """The kernel must lower (not just interpret): build the jaxpr/HLO with
-    interpret=False — no TPU execution, lowering only."""
-    x, w = _rand(128, 128, 128)
-    fn = jax.jit(lambda a, b: approx_matmul_pallas(
-        a, b, block=(128, 128, 128), interpret=True))
-    lowered = fn.lower(x, w)
-    assert "pallas" in lowered.as_text().lower() or True
-    # and the deficit path is differentiable end-to-end via quant wrapper STE
+def test_approx_backend_is_differentiable_via_ste():
+    """The approximate backends train through the straight-through
+    estimator: gradients of the quantized matmul exist and are finite.
+    (Compiles for the TPU itself are in test_tpu_compile.py.)"""
     from repro.quant.matmul import quantized_matmul
     from repro.quant.quantize import QuantConfig
     cfg = QuantConfig(backend="approx_lut")

@@ -11,7 +11,6 @@ import pytest
 from jax.sharding import Mesh
 
 from repro.parallel.pipeline import pipeline_apply
-from repro.parallel.sharding import use_mesh
 
 
 def test_pipeline_matches_sequential():
@@ -31,7 +30,7 @@ def test_pipeline_matches_sequential():
     for s in range(S):
         ref = jnp.tanh(ref @ ws[s])
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = pipeline_apply(stage_fn, mesh, ws, mb)
     err = float(jnp.max(jnp.abs(out - ref)))
     assert err < 1e-5, err

@@ -43,6 +43,24 @@ BACKENDS = list(QM.list_backends())
 MAX_LEN = 32
 
 
+@pytest.fixture(autouse=True)
+def _release_executables():
+    """Drop the compiled programs once the process nears its mapping limit.
+    Each XLA:CPU executable holds its own memory mappings, a process may
+    hold vm.max_map_count (65530 by default) of them, and this file's
+    tests, run in one process, compile enough prefill/decode programs to
+    reach that: XLA then crashes the process mid-compile."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            n_maps = sum(1 for _ in f)
+    except OSError:        # no procfs: nothing to watch
+        return
+    if n_maps > 40000:
+        clear_compiled_fns()
+        jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def tiny_lm():
     cfg = registry.reduced("smollm-135m", n_layers=2, d_model=64, n_heads=4,
@@ -644,6 +662,35 @@ def test_prefix_cache_gating(tiny_lm):
                             vocab=64, vocab_pad=64, head_dim=16)
     gparams = TLM.init(gcfg, jax.random.PRNGKey(0))
     assert Engine(gcfg, gparams, slots=1, max_len=16).prefix is None
+
+
+@pytest.mark.parametrize("spec_k", [0, 3])
+def test_bf16_params_serve_with_default_cache_dtype(spec_k):
+    # regression: the cache dtype used to default to float32 whatever the
+    # params were, and a bf16-param model then failed to trace (the layer
+    # scan's bf16 carry came back float32 from attention over an f32
+    # cache). The default now follows the params. spec_k=3 adds the
+    # speculative verify + rollback_positions path over the bf16 pool.
+    from repro.serve import SpecConfig
+    cfg = registry.reduced("smollm-135m", n_layers=2, d_model=64, n_heads=4,
+                           n_kv_heads=2, d_ff=128, vocab=64, vocab_pad=64,
+                           head_dim=16, param_dtype=jnp.bfloat16)
+    params = TLM.init(cfg, jax.random.PRNGKey(0))
+    pa, pb, pc = _shared_prompts(cfg.vocab, seed=24)
+    eng = Engine(cfg, params, slots=2, max_len=MAX_LEN, page_size=4,
+                 spec=SpecConfig(k=spec_k) if spec_k else None)
+    assert {x.dtype for x in jax.tree.leaves(eng.pool)} == {jnp.dtype(jnp.bfloat16)}
+    eng.submit(ServeRequest(rid=0, prompt=pa, max_new=4))
+    eng.run()                         # retires A, publishes its pages
+    eng.submit(ServeRequest(rid=1, prompt=pb, max_new=5))
+    eng.submit(ServeRequest(rid=2, prompt=pc, max_new=3))
+    eng.run()
+    assert eng.prefix_hit_tokens >= 16, "B and C missed the shared prefix"
+    done = {r.rid: r for r in eng.completed}
+    assert sorted(done) == [0, 1, 2]
+    for rid, max_new in [(0, 4), (1, 5), (2, 3)]:
+        assert done[rid].finish_reason == "max_new"
+        assert len(done[rid].output) == max_new
 
 
 # ---------------------------------------------------------------------------
