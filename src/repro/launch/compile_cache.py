@@ -12,17 +12,28 @@ the cache key, so a directory that moved between runs would never hit.
 from __future__ import annotations
 
 import os
+import re
 from pathlib import Path
 
 import jax
 
 # src/repro/launch/compile_cache.py -> the checkout root
-DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+ROOT = Path(__file__).resolve().parents[3]
+DEFAULT_CACHE_DIR = ROOT / ".jax_cache"
 
 
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its directory and return
-    that directory."""
+    that directory.
+
+    The cache is keyed on the programs' op metadata too, with source paths
+    taken relative to the checkout. By default JAX leaves metadata out of
+    the key, so a program whose named scopes changed would load an
+    executable compiled from older code, and a profile would show the old
+    scopes (or none) on its ops."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(str(ROOT)) + "/")
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

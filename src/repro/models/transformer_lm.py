@@ -367,22 +367,28 @@ def backbone(params, x, cfg: ArchConfig, rules: ShardingRules, *,
         def body(carry, xs):
             h, aux = carry
             lp, lc = xs
-            for i, kind in enumerate(kinds):
-                key = f"k{i}_{kind}"
-                c = None if lc is None else lc[key]
-                h, nc, a = _layer(lp[key], h, kind, cfg, rules,
-                                  cache=c, pos=pos, enc=enc, qat=qat)
-                if lc is not None:
-                    lc = dict(lc)
-                    lc[key] = nc if nc is not None else lc[key]
-                aux = aux + a
-                h = constrain(h, rules, "batch", "seq", "embed")
+            with jax.named_scope("layer"):
+                for i, kind in enumerate(kinds):
+                    key = f"k{i}_{kind}"
+                    c = None if lc is None else lc[key]
+                    h, nc, a = _layer(lp[key], h, kind, cfg, rules,
+                                      cache=c, pos=pos, enc=enc, qat=qat)
+                    if lc is not None:
+                        lc = dict(lc)
+                        lc[key] = nc if nc is not None else lc[key]
+                    aux = aux + a
+                    h = constrain(h, rules, "batch", "seq", "embed")
             return (h, aux), lc
 
         if cfg.remat and training:
             body = jax.checkpoint(body)
-        (x, aux_total), nbc = jax.lax.scan(
-            body, (x, aux_total), (bparams, bcache))
+        # op metadata only: "layer_scan" tags the loop's own slicing of
+        # each layer's weights and cache out of the stacked arrays and its
+        # write-back of the cache; "layer" the norms, residuals and
+        # activations that no projection ("qmm.*") or attention scope holds
+        with jax.named_scope("layer_scan"):
+            (x, aux_total), nbc = jax.lax.scan(
+                body, (x, aux_total), (bparams, bcache))
         new_caches.append(nbc)
     x = L.rmsnorm(params["final_ln"], x)
     return x, ({"blocks": new_caches} if caches is not None else None), \
@@ -404,15 +410,17 @@ def lm_logits(params, hidden, cfg: ArchConfig,
     matmul in the stack); multi-codebook heads stay float — the per-codebook
     einsum has no (k, n) registry lowering yet (documented in
     docs/quantization.md)."""
-    if cfg.n_codebooks > 1:
-        out = jnp.einsum("bsd,cvd->bscv", hidden, params["lm_head"]["table"],
-                         preferred_element_type=jnp.float32)
-        return constrain(out, rules, "batch", "seq", None, "vocab")
-    table = (params["lm_head"]["table"] if "lm_head" in params
-             else params["embed"]["table"])
-    out = L.logits({"table": table}, hidden, true_vocab=cfg.vocab,
-                   quant=cfg.quant, qat=qat)
-    return constrain(out, rules, "batch", "seq", "vocab")
+    with jax.named_scope("lm_head"):     # op metadata only, as qmm.*
+        if cfg.n_codebooks > 1:
+            out = jnp.einsum("bsd,cvd->bscv", hidden,
+                             params["lm_head"]["table"],
+                             preferred_element_type=jnp.float32)
+            return constrain(out, rules, "batch", "seq", None, "vocab")
+        table = (params["lm_head"]["table"] if "lm_head" in params
+                 else params["embed"]["table"])
+        out = L.logits({"table": table}, hidden, true_vocab=cfg.vocab,
+                       quant=cfg.quant, qat=qat)
+        return constrain(out, rules, "batch", "seq", "vocab")
 
 
 def forward_loss(params, batch, cfg: ArchConfig,

@@ -252,11 +252,23 @@ def apply(params, x, cfg: AttnConfig, rules: ShardingRules,
     k = k.reshape(b, kv_src.shape[1], cfg.n_kv_heads, dh)
     v = v.reshape(b, kv_src.shape[1], cfg.n_kv_heads, dh)
 
+    # "attn" scopes rotary, the cache write, scores, softmax and the value
+    # product in the compiled program's op metadata; the projections
+    # around it carry their own "qmm.*" scopes (quant/matmul.py)
+    with jax.named_scope("attn"):
+        out, new_cache = _attend(q, k, v, cfg, rules, cache, pos)
+    return L.dense({"w": params["wo"]}, out, quant, qat), new_cache
+
+
+def _attend(q, k, v, cfg: AttnConfig, rules, cache, pos):
+    """Attention proper, between the q/k/v and output projections:
+    (context (B, s, H*Dv), new cache or the cache passed in)."""
+    b, s = q.shape[:2]
     if cfg.cross:
-        enc_pos = jnp.arange(kv_src.shape[1])[None, :]
+        enc_pos = jnp.arange(k.shape[1])[None, :]
         out = _sdpa(q, k, v, jnp.zeros((b, s), jnp.int32), enc_pos, 0, rules,
                     causal=False, p_bf16=cfg.p_bf16)
-        return L.dense({"w": params["wo"]}, out, quant, qat), cache
+        return out, cache
 
     q_pos = q_positions(pos, b, s)                   # (B, s) absolute
     q = rope(q, q_pos, cfg.rope_theta)
@@ -265,7 +277,7 @@ def apply(params, x, cfg: AttnConfig, rules: ShardingRules,
     if cache is None:
         out = _sdpa(q, k, v, q_pos, q_pos, cfg.window, rules,
                     p_bf16=cfg.p_bf16)
-        return L.dense({"w": params["wo"]}, out, quant, qat), None
+        return out, None
 
     slots = cache["k"].shape[1]
     bidx = jnp.arange(b)[:, None]
@@ -285,8 +297,7 @@ def apply(params, x, cfg: AttnConfig, rules: ShardingRules,
         k_pos = jnp.where(slot_ids < written, slot_ids, -1)
     out = _sdpa(q, ck, cv, q_pos, k_pos, cfg.window, rules,
                 p_bf16=cfg.p_bf16)
-    return (L.dense({"w": params["wo"]}, out, quant, qat),
-            {"k": ck, "v": cv})
+    return out, {"k": ck, "v": cv}
 
 
 def _b(params, name):
@@ -305,80 +316,83 @@ def _apply_mla(params, x, cfg: AttnConfig, rules, quant, *, cache, pos, qat):
     dkv = L.dense({"w": params["wdkv"]}, x, quant, qat)
     ckv_new, kpe_new = dkv[..., :cfg.kv_lora], dkv[..., cfg.kv_lora:]
 
-    q_pos = q_positions(pos, b, s)                   # (B, s) absolute
-    q_pe = rope(q_pe, q_pos, cfg.rope_theta)
-    kpe_new = rope(kpe_new[:, :, None, :], q_pos, cfg.rope_theta)[:, :, 0, :]
+    with jax.named_scope("attn"):     # as in apply()
+        q_pos = q_positions(pos, b, s)                   # (B, s) absolute
+        q_pe = rope(q_pe, q_pos, cfg.rope_theta)
+        kpe_new = rope(kpe_new[:, :, None, :], q_pos,
+                       cfg.rope_theta)[:, :, 0, :]
 
-    if cache is not None:
-        bidx = jnp.arange(b)[:, None]
-        ckv = cache["ckv"].at[bidx, q_pos].set(
-            ckv_new.astype(cache["ckv"].dtype))
-        kpe = cache["kpe"].at[bidx, q_pos].set(
-            kpe_new.astype(cache["kpe"].dtype))
-        written = q_pos[:, -1:] + 1                  # (B, 1)
-        slots = ckv.shape[1]
-        slot_ids = jnp.arange(slots)[None, :]
-        k_pos = jnp.where(slot_ids < written, slot_ids, -1)  # (B, slots)
-        new_cache = {"ckv": ckv, "kpe": kpe}
-    else:
-        ckv, kpe = ckv_new, kpe_new
-        k_pos = q_pos
-        new_cache = None
+        if cache is not None:
+            bidx = jnp.arange(b)[:, None]
+            ckv = cache["ckv"].at[bidx, q_pos].set(
+                ckv_new.astype(cache["ckv"].dtype))
+            kpe = cache["kpe"].at[bidx, q_pos].set(
+                kpe_new.astype(cache["kpe"].dtype))
+            written = q_pos[:, -1:] + 1                  # (B, 1)
+            slots = ckv.shape[1]
+            slot_ids = jnp.arange(slots)[None, :]
+            k_pos = jnp.where(slot_ids < written, slot_ids, -1)  # (B, slots)
+            new_cache = {"ckv": ckv, "kpe": kpe}
+        else:
+            ckv, kpe = ckv_new, kpe_new
+            k_pos = q_pos
+            new_cache = None
 
-    # absorbed scores: q_nope^T (Wuk^T ckv)  ->  (q_nope Wuk) . ckv
-    # evaluated blockwise over KV chunks (online softmax; no (Sq,Sk) tensor)
-    q_abs = jnp.einsum("bshn,lhn->bshl", q_nope, params["wuk"],
-                       preferred_element_type=jnp.float32)
-    q_abs = q_abs * ((dn + dr) ** -0.5)
-    q_pe32 = q_pe.astype(jnp.float32) * ((dn + dr) ** -0.5)
-    sk = ckv.shape[1]
-    c = min(KV_CHUNK, sk)
-    pad = (-sk) % c
-    ckv_p = jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))) if pad else ckv
-    kpe_p = jnp.pad(kpe, ((0, 0), (0, pad), (0, 0))) if pad else kpe
-    kpos1 = jnp.broadcast_to(jnp.atleast_2d(k_pos), (b, sk))
-    kpos1 = (jnp.pad(kpos1, ((0, 0), (0, pad)), constant_values=-1)
-             if pad else kpos1)
-    n_chunks = (sk + pad) // c
-    lora = ckv.shape[-1]
-    ckv_c = ckv_p.reshape(b, n_chunks, c, lora).transpose(1, 0, 2, 3)
-    kpe_c = kpe_p.reshape(b, n_chunks, c, dr).transpose(1, 0, 2, 3)
-    kpos_c = kpos1.reshape(b, n_chunks, c).transpose(1, 0, 2)   # (n, B, c)
-    qp1 = jnp.broadcast_to(jnp.atleast_2d(q_pos), (b, s))       # (B, s)
+        # absorbed scores: q_nope^T (Wuk^T ckv)  ->  (q_nope Wuk) . ckv
+        # evaluated blockwise over KV chunks (online softmax; no (Sq,Sk)
+        # tensor)
+        q_abs = jnp.einsum("bshn,lhn->bshl", q_nope, params["wuk"],
+                           preferred_element_type=jnp.float32)
+        q_abs = q_abs * ((dn + dr) ** -0.5)
+        q_pe32 = q_pe.astype(jnp.float32) * ((dn + dr) ** -0.5)
+        sk = ckv.shape[1]
+        c = min(KV_CHUNK, sk)
+        pad = (-sk) % c
+        ckv_p = jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))) if pad else ckv
+        kpe_p = jnp.pad(kpe, ((0, 0), (0, pad), (0, 0))) if pad else kpe
+        kpos1 = jnp.broadcast_to(jnp.atleast_2d(k_pos), (b, sk))
+        kpos1 = (jnp.pad(kpos1, ((0, 0), (0, pad)), constant_values=-1)
+                 if pad else kpos1)
+        n_chunks = (sk + pad) // c
+        lora = ckv.shape[-1]
+        ckv_c = ckv_p.reshape(b, n_chunks, c, lora).transpose(1, 0, 2, 3)
+        kpe_c = kpe_p.reshape(b, n_chunks, c, dr).transpose(1, 0, 2, 3)
+        kpos_c = kpos1.reshape(b, n_chunks, c).transpose(1, 0, 2)   # (n, B, c)
+        qp1 = jnp.broadcast_to(jnp.atleast_2d(q_pos), (b, s))       # (B, s)
 
-    def _c3(t):   # (B, H, Sq[, lora]) carries
-        return constrain(t, rules, "batch", "heads",
-                         *([None] * (t.ndim - 2)))
+        def _c3(t):   # (B, H, Sq[, lora]) carries
+            return constrain(t, rules, "batch", "heads",
+                             *([None] * (t.ndim - 2)))
 
-    m0 = _c3(jnp.full((b, h, s), NEG, jnp.float32))
-    l0 = _c3(jnp.zeros((b, h, s), jnp.float32))
-    a0 = _c3(jnp.zeros((b, h, s, lora), jnp.float32))
+        m0 = _c3(jnp.full((b, h, s), NEG, jnp.float32))
+        l0 = _c3(jnp.zeros((b, h, s), jnp.float32))
+        a0 = _c3(jnp.zeros((b, h, s, lora), jnp.float32))
 
-    def body(carry, xs):
-        m, l, acc = carry
-        ckv_j, kpe_j, kpj = xs                          # kpj (B, c)
-        dist = qp1[:, :, None] - kpj[:, None, :]        # (B, Sq, c)
-        mj = (kpj[:, None, :] >= 0) & (dist >= 0)
-        sc = (jnp.einsum("bshl,bkl->bhsk", q_abs,
-                         ckv_j.astype(jnp.float32))
-              + jnp.einsum("bshr,bkr->bhsk", q_pe32,
-                           kpe_j.astype(jnp.float32)))
-        sc = jnp.where(mj[:, None], sc, NEG)
-        m_new = jnp.maximum(m, sc.max(axis=-1))
-        p = jnp.exp(sc - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l = _c3(l * corr + p.sum(axis=-1))
-        pv = p.astype(jnp.bfloat16) if cfg.p_bf16 else p
-        acc = _c3(acc * corr[..., None] + jnp.einsum(
-            "bhsk,bkl->bhsl", pv, ckv_j,
-            preferred_element_type=jnp.float32))
-        return (_c3(m_new), l, acc), None
+        def body(carry, xs):
+            m, l, acc = carry
+            ckv_j, kpe_j, kpj = xs                          # kpj (B, c)
+            dist = qp1[:, :, None] - kpj[:, None, :]        # (B, Sq, c)
+            mj = (kpj[:, None, :] >= 0) & (dist >= 0)
+            sc = (jnp.einsum("bshl,bkl->bhsk", q_abs,
+                             ckv_j.astype(jnp.float32))
+                  + jnp.einsum("bshr,bkr->bhsk", q_pe32,
+                               kpe_j.astype(jnp.float32)))
+            sc = jnp.where(mj[:, None], sc, NEG)
+            m_new = jnp.maximum(m, sc.max(axis=-1))
+            p = jnp.exp(sc - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l = _c3(l * corr + p.sum(axis=-1))
+            pv = p.astype(jnp.bfloat16) if cfg.p_bf16 else p
+            acc = _c3(acc * corr[..., None] + jnp.einsum(
+                "bhsk,bkl->bhsl", pv, ckv_j,
+                preferred_element_type=jnp.float32))
+            return (_c3(m_new), l, acc), None
 
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
-                                  (ckv_c, kpe_c, kpos_c))
-    ctx = (acc / jnp.maximum(l[..., None], 1e-30)).transpose(0, 2, 1, 3)
-    out = jnp.einsum("bshl,lhv->bshv", ctx.astype(x.dtype), params["wuv"],
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    out = out.reshape(b, s, h * cfg.v_head_dim)
-    out = constrain(out, rules, "batch", "seq", "heads")
+        (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
+                                      (ckv_c, kpe_c, kpos_c))
+        ctx = (acc / jnp.maximum(l[..., None], 1e-30)).transpose(0, 2, 1, 3)
+        out = jnp.einsum("bshl,lhv->bshv", ctx.astype(x.dtype), params["wuv"],
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+        out = out.reshape(b, s, h * cfg.v_head_dim)
+        out = constrain(out, rules, "batch", "seq", "heads")
     return L.dense({"w": params["wo"]}, out, quant, qat), new_cache

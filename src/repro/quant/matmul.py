@@ -633,11 +633,16 @@ def _qmm_forward(x, w, bias, cfg: QuantConfig, activation):
     if not per_token and cfg.act_scale != "per_tensor":
         raise ValueError(f"unknown act_scale {cfg.act_scale!r}; "
                          "choose 'per_tensor' or 'per_token'")
-    if cfg.per_channel:
-        sw = abs_max_scale(w, axis=0, keepdims=True)   # (1, n)
-    else:
-        sw = abs_max_scale(w)
-    w_q = quantize(w, sw)
+    # named scopes tag each phase in the compiled program's op metadata
+    # (metadata only: the computation is unchanged), so a profile can
+    # split a projection's device time into weight quantization,
+    # activation quantization, the integer core and the dequant epilogue
+    with jax.named_scope("qmm.wquant"):
+        if cfg.per_channel:
+            sw = abs_max_scale(w, axis=0, keepdims=True)   # (1, n)
+        else:
+            sw = abs_max_scale(w)
+        w_q = quantize(w, sw)
 
     if backend.fused is not None and cfg.fuse_epilogue:
         # (B, T, K): leading dims become the kernel's batch grid axis
@@ -646,34 +651,46 @@ def _qmm_forward(x, w, bias, cfg: QuantConfig, activation):
         else:
             x3 = x.reshape(-1, x.shape[-2], k)
         if per_token:
-            sx = abs_max_scale(x3, axis=-1, keepdims=True)  # (..., M, 1)
-            x_q = quantize(x3, sx)
-            scale = jnp.broadcast_to(
-                jnp.asarray(sw, jnp.float32).reshape(1, -1), (1, n))
-            y = backend.fused(x_q, w_q, cfg, scale,
-                              jnp.zeros((1, n), jnp.float32), False)
-            y = _float_epilogue(_pin(_pin(y) * sx), bias, activation)
+            with jax.named_scope("qmm.xquant"):
+                sx = abs_max_scale(x3, axis=-1, keepdims=True)  # (..., M, 1)
+                x_q = quantize(x3, sx)
+            with jax.named_scope("qmm.dequant"):
+                scale = jnp.broadcast_to(
+                    jnp.asarray(sw, jnp.float32).reshape(1, -1), (1, n))
+            with jax.named_scope("qmm.core"):
+                y = backend.fused(x_q, w_q, cfg, scale,
+                                  jnp.zeros((1, n), jnp.float32), False)
+            with jax.named_scope("qmm.dequant"):
+                y = _float_epilogue(_pin(_pin(y) * sx), bias, activation)
         else:
-            sx = abs_max_scale(x3, axis=None, keepdims=False)
-            x_q = quantize(x3, sx)
-            scale = jnp.broadcast_to((sx * sw).reshape(1, -1), (1, n))
-            b_arr = (jnp.zeros((1, n), jnp.float32) if bias is None
-                     else bias.astype(jnp.float32).reshape(1, n))
-            y = backend.fused(x_q, w_q, cfg, scale, b_arr,
-                              activation == "relu")
+            with jax.named_scope("qmm.xquant"):
+                sx = abs_max_scale(x3, axis=None, keepdims=False)
+                x_q = quantize(x3, sx)
+            with jax.named_scope("qmm.dequant"):
+                scale = jnp.broadcast_to((sx * sw).reshape(1, -1), (1, n))
+                b_arr = (jnp.zeros((1, n), jnp.float32) if bias is None
+                         else bias.astype(jnp.float32).reshape(1, n))
+            with jax.named_scope("qmm.core"):
+                y = backend.fused(x_q, w_q, cfg, scale, b_arr,
+                                  activation == "relu")
     else:
-        x2 = x.reshape(-1, k)
-        sx = abs_max_scale(x2, axis=-1 if per_token else None,
-                           keepdims=per_token)   # (M, 1) | scalar
-        x_q = quantize(x2, sx)
-        acc = backend.fn(x_q, w_q, cfg).astype(jnp.float32)
-        if per_token:
-            # pinned order: weight scale, then row scale (see _pin)
-            y = _pin(_pin(acc * sw) * sx)
-        else:
-            y = acc * (sx * sw)
-        y = _float_epilogue(y, bias, activation)
-    return y.reshape(*lead, n).astype(x.dtype)
+        with jax.named_scope("qmm.xquant"):
+            x2 = x.reshape(-1, k)
+            sx = abs_max_scale(x2, axis=-1 if per_token else None,
+                               keepdims=per_token)   # (M, 1) | scalar
+            x_q = quantize(x2, sx)
+        with jax.named_scope("qmm.core"):
+            acc = backend.fn(x_q, w_q, cfg)
+        with jax.named_scope("qmm.dequant"):
+            acc = acc.astype(jnp.float32)
+            if per_token:
+                # pinned order: weight scale, then row scale (see _pin)
+                y = _pin(_pin(acc * sw) * sx)
+            else:
+                y = acc * (sx * sw)
+            y = _float_epilogue(y, bias, activation)
+    with jax.named_scope("qmm.dequant"):
+        return y.reshape(*lead, n).astype(x.dtype)
 
 
 def _qmm_grads(x, w, y, g, activation):

@@ -404,60 +404,68 @@ class Engine:
         return min(bucket, self.max_len - offset)
 
     def _admit(self) -> None:
-        for slot, req in self.sched.admit():
-            plen = len(req.prompt)
-            if plen > self.max_len:
-                # rejected before prefill: no room for even the prompt
-                req.finish_reason = "max_len"
-                self._retire(slot, store=False)
-                continue
-            # longest cached full-page prefix, capped at plen-1 so at
-            # least one suffix token remains to produce the first logits
-            chain: Tuple[int, ...] = ()
-            hit = 0
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            for slot, req in self.sched.admit():
+                self._admit_one(slot, req)
+
+    def _admit_one(self, slot: int, req: ServeRequest) -> None:
+        plen = len(req.prompt)
+        if plen > self.max_len:
+            # rejected before prefill: no room for even the prompt
+            req.finish_reason = "max_len"
+            self._retire(slot, store=False)
+            return
+        # longest cached full-page prefix, capped at plen-1 so at least
+        # one suffix token remains to produce the first logits
+        chain: Tuple[int, ...] = ()
+        hit = 0
+        with jax.profiler.TraceAnnotation("serve.match"):
             if self.prefix is not None:
                 chain = tuple(self.prefix.match(req.prompt[:plen - 1]))
                 hit = len(chain) * self.page_size
                 if chain:
                     self.prefix.acquire(chain)   # pinned until retirement
                     self.prefix_hit_tokens += hit
-            self._slot_chain[slot] = chain
-            suffix = req.prompt[hit:]
-            bucket = self._bucket(len(suffix), offset=hit)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :len(suffix)] = suffix
+        self._slot_chain[slot] = chain
+        suffix = req.prompt[hit:]
+        bucket = self._bucket(len(suffix), offset=hit)
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :len(suffix)] = suffix
+        with jax.profiler.TraceAnnotation("serve.gather"):
             fresh = TLM.init_cache(self.cfg, 1, self.max_len,
                                    self._cache_dtype)
             if chain:
                 # the COW copy: shared pages -> this request's private row
                 fresh = TLM.gather_pages(fresh, self.pages, chain)
+        with jax.profiler.TraceAnnotation("serve.prefill"):
             logits, fresh = self._prefill(
                 self.params, jnp.asarray(toks), fresh,
                 jnp.asarray([len(suffix)], jnp.int32), jnp.int32(hit))
-            self.prefills += 1
-            self.prefill_tokens += len(suffix)
-            # full-row copy: the freed slot inherits nothing from its
-            # previous occupant (zero KV-cache leakage on reuse)
+        self.prefills += 1
+        self.prefill_tokens += len(suffix)
+        # full-row copy: the freed slot inherits nothing from its previous
+        # occupant (zero KV-cache leakage on reuse)
+        with jax.profiler.TraceAnnotation("serve.write_slot"):
             if self._pool_write is not None:
                 self.pool = self._pool_write(self.pool, fresh,
                                              jnp.int32(slot))
             else:
                 self.pool = _write_slot(self.pool, fresh, slot)
-            self._slot_req[slot] = req
-            self._pos[slot] = plen
-            if req.max_new <= 0:
-                req.finish_reason = "max_new"
-            else:
-                first = sample_token(logits[0, 0], req.sampling, req.rid, 0)
-                self._emit(req, first)
-            if req.finish_reason:
-                self._retire(slot)
-            else:
-                self._tok[slot] = req.output[-1]
-                if self.speculator is not None:
-                    # draft-side cold prefill of the full prompt (the
-                    # draft never reads the paged prefix store)
-                    self.speculator.admit(slot, req.prompt, self._bucket)
+        self._slot_req[slot] = req
+        self._pos[slot] = plen
+        if req.max_new <= 0:
+            req.finish_reason = "max_new"
+        else:
+            first = sample_token(logits[0, 0], req.sampling, req.rid, 0)
+            self._emit(req, first)
+        if req.finish_reason:
+            self._retire(slot)
+        else:
+            self._tok[slot] = req.output[-1]
+            if self.speculator is not None:
+                # draft-side cold prefill of the full prompt (the draft
+                # never reads the paged prefix store)
+                self.speculator.admit(slot, req.prompt, self._bucket)
 
     # ---- token emission / finish ----------------------------------------
     def _emit(self, req: ServeRequest, tok: int) -> None:
@@ -476,19 +484,21 @@ class Engine:
             req.finish_reason = "max_len"
 
     def _retire(self, slot: int, store: bool = True) -> None:
-        req = self.sched.release(slot)
-        req.timing.done_t = time.time()
-        if self.prefix is not None:
-            if store:
-                self._store_pages(slot, req)
-            if self._slot_chain[slot]:
-                self.prefix.release(self._slot_chain[slot])
-            self._slot_chain[slot] = ()
-        self._slot_req[slot] = None
-        self._tok[slot] = 0
-        self._pos[slot] = 0     # park: writes land at pos 0 of a dead row
-        #                         and are overwritten by the next admission
-        self.completed.append(req)
+        with jax.profiler.TraceAnnotation("serve.retire"):
+            req = self.sched.release(slot)
+            req.timing.done_t = time.time()
+            if self.prefix is not None:
+                if store:
+                    self._store_pages(slot, req)
+                if self._slot_chain[slot]:
+                    self.prefix.release(self._slot_chain[slot])
+                self._slot_chain[slot] = ()
+            self._slot_req[slot] = None
+            self._tok[slot] = 0
+            self._pos[slot] = 0     # park: writes land at pos 0 of a dead
+            #                         row and are overwritten by the next
+            #                         admission
+            self.completed.append(req)
 
     def _store_pages(self, slot: int, req: ServeRequest) -> None:
         """Publish this request's KV to the prefix cache. KV exists for
@@ -497,16 +507,19 @@ class Engine:
         cacheable key is prompt ++ output[:-1]."""
         seq = req.prompt if not req.output else np.concatenate(
             [req.prompt, np.asarray(req.output[:-1], np.int32)])
-        new = self.prefix.insert(seq)
+        with jax.profiler.TraceAnnotation("serve.publish"):
+            new = self.prefix.insert(seq)   # radix insert and evictions
         if new:
-            self.pages = TLM.store_pages(
-                self.pages, self.pool, slot,
-                [p for p, _ in new], [i for _, i in new])
-            if self.mesh is not None:
-                # keep the store's head/page sharding pinned (the eager
-                # scatter above follows GSPMD propagation, not our layout)
-                self.pages = jax.device_put(self.pages,
-                                            self._pages_shardings)
+            with jax.profiler.TraceAnnotation("serve.store_pages"):
+                self.pages = TLM.store_pages(
+                    self.pages, self.pool, slot,
+                    [p for p, _ in new], [i for _, i in new])
+                if self.mesh is not None:
+                    # keep the store's head/page sharding pinned (the eager
+                    # scatter above follows GSPMD propagation, not our
+                    # layout)
+                    self.pages = jax.device_put(self.pages,
+                                                self._pages_shardings)
 
     # ---- the serving loop ------------------------------------------------
     def _spec_eligible(self, active: List[int]) -> bool:
@@ -528,34 +541,44 @@ class Engine:
         """Admit into free slots, then one decode step over the whole pool
         — a (slots, K) speculative verify pass when configured and in
         bounds, a (slots, 1) sequential step otherwise. Returns False once
-        queue and pool are both empty."""
-        self._admit()
-        active = [s for s in range(self.slots) if self._slot_req[s]]
-        if not active:
-            return not self.sched.idle
-        if self._spec_eligible(active):
-            self._spec_step(active)
+        queue and pool are both empty.
+
+        Each phase runs inside a ``jax.profiler.TraceAnnotation`` named
+        ``serve.*`` (one per step, admission or retirement), so a profiler
+        trace shows where the host time of a step went; with no profiler
+        running each costs about a microsecond."""
+        with jax.profiler.TraceAnnotation("serve.step"):
+            self._admit()
+            active = [s for s in range(self.slots) if self._slot_req[s]]
+            if not active:
+                return not self.sched.idle
+            if self._spec_eligible(active):
+                self._spec_step(active)
+                return True
+            if self.speculator is not None:
+                # keep the draft pool on the true stream through the
+                # fallback
+                self.speculator.advance(self._tok, self._pos)
+            with jax.profiler.TraceAnnotation("serve.decode"):
+                logits, self.pool = self._decode(
+                    self.params, self.pool, jnp.asarray(self._tok[:, None]),
+                    jnp.asarray(self._pos))
+            self.decode_steps += 1
+            self.busy_slot_steps += len(active)
+            with jax.profiler.TraceAnnotation("serve.logits"):
+                rows = np.asarray(logits[:, 0])     # one host transfer
+            with jax.profiler.TraceAnnotation("serve.sample"):
+                for s in active:
+                    req = self._slot_req[s]
+                    self._pos[s] += 1
+                    tok = sample_token(rows[s], req.sampling, req.rid,
+                                       len(req.output))
+                    self._emit(req, tok)
+                    if req.finish_reason:
+                        self._retire(s)
+                    else:
+                        self._tok[s] = tok
             return True
-        if self.speculator is not None:
-            # keep the draft pool on the true stream through the fallback
-            self.speculator.advance(self._tok, self._pos)
-        logits, self.pool = self._decode(
-            self.params, self.pool, jnp.asarray(self._tok[:, None]),
-            jnp.asarray(self._pos))
-        self.decode_steps += 1
-        self.busy_slot_steps += len(active)
-        rows = np.asarray(logits[:, 0])             # one host transfer
-        for s in active:
-            req = self._slot_req[s]
-            self._pos[s] += 1
-            tok = sample_token(rows[s], req.sampling, req.rid,
-                               len(req.output))
-            self._emit(req, tok)
-            if req.finish_reason:
-                self._retire(s)
-            else:
-                self._tok[s] = tok
-        return True
 
     def _spec_step(self, active: List[int]) -> None:
         """One draft-propose / target-verify / commit / rollback pass.
@@ -571,38 +594,41 @@ class Engine:
         k = spec.spec.k
         p0 = self._pos.copy()
         window = spec.propose(self._tok, self._pos)
-        logits, self.pool = self._decode(
-            self.params, self.pool, jnp.asarray(window),
-            jnp.asarray(self._pos))
+        with jax.profiler.TraceAnnotation("serve.decode"):
+            logits, self.pool = self._decode(
+                self.params, self.pool, jnp.asarray(window),
+                jnp.asarray(self._pos))
         self.decode_steps += 1
         self.busy_slot_steps += len(active)
         spec.metrics.passes += 1
-        rows = np.asarray(logits)                   # (slots, K, V)
+        with jax.profiler.TraceAnnotation("serve.logits"):
+            rows = np.asarray(logits)               # (slots, K, V)
         frontier = p0.copy()                        # rollback start/slot
         retired: List[int] = []
-        for s in active:
-            req = self._slot_req[s]
-            cap = k if req.spec_k is None else 1 + min(max(req.spec_k, 0),
-                                                       k - 1)
-            emitted = 0
-            for j in range(cap):
-                tok = sample_token(rows[s, j], req.sampling, req.rid,
-                                   len(req.output))
-                self._emit(req, tok)
-                emitted += 1
+        with jax.profiler.TraceAnnotation("serve.sample"):
+            for s in active:
+                req = self._slot_req[s]
+                cap = (k if req.spec_k is None
+                       else 1 + min(max(req.spec_k, 0), k - 1))
+                emitted = 0
+                for j in range(cap):
+                    tok = sample_token(rows[s, j], req.sampling, req.rid,
+                                       len(req.output))
+                    self._emit(req, tok)
+                    emitted += 1
+                    if req.finish_reason:
+                        break
+                    # continue only while the next verified row consumed
+                    # this exact token (the draft proposal at window j+1)
+                    if j + 1 >= cap or tok != window[s, j + 1]:
+                        break
+                spec.metrics.record(drafted=cap - 1, committed=emitted)
+                frontier[s] = p0[s] + emitted
                 if req.finish_reason:
-                    break
-                # continue only while the next verified row consumed
-                # this exact token (the draft proposal at window j+1)
-                if j + 1 >= cap or tok != window[s, j + 1]:
-                    break
-            spec.metrics.record(drafted=cap - 1, committed=emitted)
-            frontier[s] = p0[s] + emitted
-            if req.finish_reason:
-                retired.append(s)
-            else:
-                self._tok[s] = req.output[-1]
-                self._pos[s] = p0[s] + emitted
+                    retired.append(s)
+                else:
+                    self._tok[s] = req.output[-1]
+                    self._pos[s] = p0[s] + emitted
         # un-commit rejected positions [frontier, p0 + K) in both pools.
         # Parked rows (frontier == p0 == 0 stays) collected junk at
         # [0, K) during the pass — erased the same way.
