@@ -23,7 +23,13 @@ Sharing model (copy-on-write at admission granularity):
   * refcounts: the tree holds one reference per published page; live
     requests pin (incref) their matched chain from admission to retirement
     so eviction can never recycle a page mid-flight. Eviction only
-    considers leaf nodes with refcount 1 (tree-only), LRU first.
+    considers leaf nodes with refcount 1 (tree-only), LRU first. Those
+    candidates sit in a min-heap keyed by ``last_used``: a node is pushed
+    whenever it becomes a candidate (its last child evicted, its refcount
+    back to 1) or is touched while one; an entry that no longer describes
+    a candidate at its key is dropped when popped (lazy deletion), and the
+    heap is rebuilt from the live candidates when stale entries outgrow
+    the tree. An eviction is O(log n) amortised, not a walk of the tree.
 
 KV reusability is exactly prefix-deep: the KV written at position ``i`` is
 a pure function of tokens ``0..i`` (per-token activation scales make the
@@ -83,11 +89,15 @@ class PagePool:
             heapq.heappush(self._free, page)
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class _Node:
-    """One radix-tree edge: a full page of token ids -> its page."""
+    """One radix-tree edge: a full page of token ids -> its page. ``chunk``
+    is this node's key in its parent's ``children`` (the root dict when
+    ``parent`` is None)."""
     page: int
     last_used: int
+    chunk: Tuple[int, ...]
+    parent: Optional["_Node"]
     children: Dict[Tuple[int, ...], "_Node"] = dataclasses.field(
         default_factory=dict)
 
@@ -98,9 +108,15 @@ class PrefixCache:
     ``match`` returns the longest cached chain of full pages; ``insert``
     publishes a finished sequence, allocating pages only for the chunks the
     tree does not already hold (the caller copies the KV for exactly the
-    returned assignments). Both run in O(len(tokens) / page_size) dict
-    hops.
+    returned assignments). ``match`` runs in O(len(tokens) / page_size)
+    dict hops and heap pushes; ``insert`` adds O(log n) amortised per
+    evicted page (n = nodes in the tree), popping the LRU evictable leaf
+    from the candidate heap. Pins go through ``acquire``/``release``: the
+    heap learns of a refcount returning to 1 there, not from the pool.
     """
+
+    # the heap is rebuilt once it holds more than 2 * nodes + this entries
+    _HEAP_SLACK = 64
 
     def __init__(self, page_size: int, n_pages: int):
         if page_size < 1:
@@ -108,10 +124,14 @@ class PrefixCache:
         self.page_size = page_size
         self.pool = PagePool(n_pages)
         self._root: Dict[Tuple[int, ...], _Node] = {}
+        self._by_page: Dict[int, _Node] = {}   # every node, by its page
+        # (last_used, node) for every eviction candidate, plus stale entries
+        self._heap: List[Tuple[int, _Node]] = []
         self._clock = 0
         self.hits = 0            # match() calls returning >= 1 page
         self.misses = 0
         self.evictions = 0
+        self.stale_pops = 0      # heap entries discarded when popped
 
     # ---- helpers ---------------------------------------------------------
     def _chunks(self, tokens: Sequence[int]) -> List[Tuple[int, ...]]:
@@ -124,22 +144,34 @@ class PrefixCache:
         self._clock += 1
         return self._clock
 
-    def _nodes(self):
-        """(parent_children_dict, chunk, node) for every node, DFS."""
-        stack = [(self._root, c, n) for c, n in self._root.items()]
-        while stack:
-            parent, chunk, node = stack.pop()
-            yield parent, chunk, node
-            stack.extend((node.children, c, n)
-                         for c, n in node.children.items())
+    def _is_candidate(self, node: _Node) -> bool:
+        """In the tree, a leaf, and held by the tree alone."""
+        return (self._by_page.get(node.page) is node
+                and not node.children
+                and self.pool.refcount(node.page) == 1)
+
+    def _push(self, node: _Node) -> None:
+        """Record ``node`` at its current ``last_used`` if it is a
+        candidate; every transition into candidacy calls this."""
+        if self._is_candidate(node):
+            heapq.heappush(self._heap, (node.last_used, node))
+            self._bound_heap()
+
+    def _bound_heap(self) -> None:
+        """Rebuild the heap from the live candidates once stale entries
+        outgrow the tree: O(n) at most once per ~n pushes or evictions."""
+        if len(self._heap) > 2 * len(self._by_page) + self._HEAP_SLACK:
+            self._heap = [(n.last_used, n) for n in self._by_page.values()
+                          if self._is_candidate(n)]
+            heapq.heapify(self._heap)
 
     @property
     def n_nodes(self) -> int:
-        return sum(1 for _ in self._nodes())
+        return len(self._by_page)
 
     def pages(self) -> List[int]:
         """Every page id currently published in the tree (sorted)."""
-        return sorted(n.page for _, _, n in self._nodes())
+        return sorted(self._by_page)
 
     # ---- the cache operations --------------------------------------------
     def match(self, tokens: Sequence[int]) -> List[int]:
@@ -156,6 +188,7 @@ class PrefixCache:
             if node is None:
                 break
             node.last_used = self._tick()
+            self._push(node)
             chain.append(node.page)
             level = node.children
         if chain:
@@ -172,6 +205,7 @@ class PrefixCache:
     def release(self, chain: Sequence[int]) -> None:
         for page in chain:
             self.pool.decref(page)
+            self._push(self._by_page[page])
 
     def insert(self, tokens: Sequence[int]) -> List[Tuple[int, int]]:
         """Publish ``tokens``; returns [(page_id, page_index), ...] for the
@@ -181,7 +215,8 @@ class PrefixCache:
         untouched. Stops early (keeping the tree prefix-closed) when the
         pool is exhausted and nothing is evictable."""
         new: List[Tuple[int, int]] = []
-        pinned: List[int] = []
+        pinned: List[_Node] = []
+        parent: Optional[_Node] = None
         level = self._root
         for idx, chunk in enumerate(self._chunks(tokens)):
             node = level.get(chunk)
@@ -189,8 +224,10 @@ class PrefixCache:
                 page = self._alloc_with_eviction()
                 if page is None:
                     break
-                node = _Node(page=page, last_used=self._tick())
+                node = _Node(page=page, last_used=self._tick(), chunk=chunk,
+                             parent=parent)
                 level[chunk] = node
+                self._by_page[page] = node
                 new.append((page, idx))
             else:
                 node.last_used = self._tick()
@@ -199,10 +236,11 @@ class PrefixCache:
             # just-inserted node is a refcount-1 leaf — evicting it would
             # recycle its page into the next chunk and orphan the subtree)
             self.pool.incref(node.page)
-            pinned.append(node.page)
-            level = node.children
-        for page in pinned:
-            self.pool.decref(page)
+            pinned.append(node)
+            parent, level = node, node.children
+        for node in pinned:
+            self.pool.decref(node.page)
+            self._push(node)
         return new
 
     # ---- eviction --------------------------------------------------------
@@ -214,22 +252,26 @@ class PrefixCache:
 
     def _evict_one(self) -> bool:
         """Drop the least-recently-used evictable leaf (refcount 1 — held
-        only by the tree; pinned chains of live requests never qualify)."""
-        victim = None
-        for parent, chunk, node in self._nodes():
-            if node.children or self.pool.refcount(node.page) != 1:
-                continue
-            if victim is None or node.last_used < victim[2].last_used:
-                victim = (parent, chunk, node)
-        if victim is None:
+        only by the tree; pinned chains of live requests never qualify).
+        ``last_used`` is unique per node, so the victim is too."""
+        while self._heap:
+            last_used, node = heapq.heappop(self._heap)
+            if node.last_used == last_used and self._is_candidate(node):
+                break
+            self.stale_pops += 1
+        else:
             return False
-        parent, chunk, node = victim
-        del parent[chunk]
+        parent = node.parent
+        del (self._root if parent is None else parent.children)[node.chunk]
+        del self._by_page[node.page]
         self.pool.decref(node.page)
         self.evictions += 1
+        if parent is not None:
+            self._push(parent)           # may have lost its last child
+        self._bound_heap()
         return True
 
     def stats(self) -> Dict[str, int]:
         return {"nodes": self.n_nodes, "free_pages": self.pool.n_free,
                 "hits": self.hits, "misses": self.misses,
-                "evictions": self.evictions}
+                "evictions": self.evictions, "stale_pops": self.stale_pops}

@@ -17,9 +17,10 @@ that make it true are each pinned here:
   * FIFO slot scheduler (property-tested: conservation, capacity, no
     starvation under random arrival orders)
   * paged prefix cache (property-tested bookkeeping: refcount
-    conservation, no page aliasing, pinned chains never evicted — and the
-    engine-level contract: a cache-hit decode is bitwise equal to the
-    cold-miss decode, per backend)
+    conservation, no page aliasing, pinned chains never evicted, the
+    candidate heap evicting exactly a whole-tree walk's victims in O(1)
+    pops per page — and the engine-level contract: a cache-hit decode is
+    bitwise equal to the cold-miss decode, per backend)
 """
 import dataclasses
 
@@ -556,6 +557,146 @@ def test_prefix_cache_eviction_spares_pinned_chains():
     assert cache.evictions >= 3
     # the ledger still balances after evictions
     assert len(cache.pages()) + cache.pool.n_free == 4
+
+
+class _WalkLRUCache:
+    """The prefix cache's contract written the plain way: a radix tree of
+    dict nodes whose eviction walks every node for the least-recently-used
+    leaf held by the tree alone. PrefixCache must pick the same victims."""
+
+    def __init__(self, page_size, n_pages):
+        self.page_size = page_size
+        self.pool = PagePool(n_pages)
+        self.root = {}              # chunk -> [page, last_used, children]
+        self.clock = 0
+        self.evictions = 0
+
+    def _chunks(self, tokens):
+        ps = self.page_size
+        return [tuple(tokens[i:i + ps])
+                for i in range(0, len(tokens) - len(tokens) % ps, ps)]
+
+    def _tick(self):
+        self.clock += 1
+        return self.clock
+
+    def _walk(self):
+        stack = [(self.root, c, n) for c, n in self.root.items()]
+        while stack:
+            parent, chunk, node = stack.pop()
+            yield parent, chunk, node
+            stack.extend((node[2], c, n) for c, n in node[2].items())
+
+    def pages(self):
+        return sorted(node[0] for _, _, node in self._walk())
+
+    def match(self, tokens):
+        chain, level = [], self.root
+        for chunk in self._chunks(tokens):
+            node = level.get(chunk)
+            if node is None:
+                break
+            node[1] = self._tick()
+            chain.append(node[0])
+            level = node[2]
+        return chain
+
+    def insert(self, tokens):
+        new, pinned, level = [], [], self.root
+        for idx, chunk in enumerate(self._chunks(tokens)):
+            node = level.get(chunk)
+            if node is None:
+                page = self.pool.alloc()
+                while page is None and self._evict_one():
+                    page = self.pool.alloc()
+                if page is None:
+                    break
+                node = level[chunk] = [page, self._tick(), {}]
+                new.append((page, idx))
+            else:
+                node[1] = self._tick()
+            self.pool.incref(node[0])
+            pinned.append(node[0])
+            level = node[2]
+        for page in pinned:
+            self.pool.decref(page)
+        return new
+
+    def _evict_one(self):
+        victim = None
+        for parent, chunk, node in self._walk():
+            if node[2] or self.pool.refcount(node[0]) != 1:
+                continue
+            if victim is None or node[1] < victim[2][1]:
+                victim = (parent, chunk, node)
+        if victim is None:
+            return False
+        parent, chunk, node = victim
+        del parent[chunk]
+        self.pool.decref(node[0])
+        self.evictions += 1
+        return True
+
+
+@settings(max_examples=200, deadline=None)
+@given(st.lists(st.lists(st.integers(0, 3), min_size=1, max_size=10),
+                min_size=20, max_size=60),
+       st.integers(1, 3), st.integers(1, 6), st.integers(0, 1))
+def test_prefix_cache_evicts_the_walks_victims(steps, page_size, n_pages,
+                                               rebuild_often):
+    # random match / acquire / insert / release orders over a tiny alphabet
+    # (deep shared prefixes), with pins held across inserts; a step's first
+    # draw picks the operation, the rest are its tokens. With no slack the
+    # candidate heap is rebuilt at nearly every push, so rebuilds are
+    # checked too; with the default slack stale entries pile up.
+    cache = PrefixCache(page_size, n_pages)
+    if rebuild_often:
+        cache._HEAP_SLACK = 0
+    oracle = _WalkLRUCache(page_size, n_pages)
+    pins = []
+    for op, *toks in steps:
+        if op in (0, 3):                 # match, and pin unless op 3
+            chain = cache.match(toks)
+            assert chain == oracle.match(toks)
+            if chain and op == 0:
+                cache.acquire(chain)
+                for page in chain:
+                    oracle.pool.incref(page)
+                pins.append(chain)
+        elif op == 1 and pins:           # release one of the pins
+            chain = pins.pop(len(toks) % len(pins))
+            cache.release(chain)
+            for page in chain:
+                oracle.pool.decref(page)
+        else:
+            assert cache.insert(toks) == oracle.insert(toks)
+        assert cache.pages() == oracle.pages()
+        assert cache.n_nodes == len(oracle.pages())
+        assert cache.evictions == oracle.evictions
+        assert [cache.pool.refcount(p) for p in range(n_pages)] == \
+            [oracle.pool.refcount(p) for p in range(n_pages)]
+        assert len(cache._heap) <= 2 * cache.n_nodes + cache._HEAP_SLACK
+
+
+def test_prefix_cache_eviction_pops_a_constant_per_page():
+    # the benchmark's store: 16384 pages of 8 tokens filled with 32-page
+    # chains, so that each fresh 256-token sequence evicts 32 pages; the
+    # candidate heap must find each victim in O(1) pops, not a tree walk
+    rng = np.random.default_rng(0)
+    cache = PrefixCache(8, 16384)
+    while cache.pool.n_free:
+        cache.insert(rng.integers(1, 49152, 32 * 8))
+    assert cache.evictions == 0 and cache.n_nodes == 16384
+    bound = 2 * cache.n_nodes + cache._HEAP_SLACK
+    for _ in range(20):
+        seq = rng.integers(1, 49152, 256)
+        assert len(cache.insert(seq)) == 32
+        assert len(cache.match(seq)) == 32      # a hit re-keys the leaf
+        assert len(cache._heap) <= bound
+    assert cache.evictions == 20 * 32
+    assert cache.stale_pops <= cache.evictions
+    assert cache.stats()["stale_pops"] == cache.stale_pops
+    assert len(cache.pages()) + cache.pool.n_free == 16384
 
 
 # ---------------------------------------------------------------------------
