@@ -26,7 +26,6 @@ def main(cells):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     import harness
-    import weights as W
     from repro.models import transformer_lm as TLM
     from repro.parallel.sharding import DEFAULT_RULES
     from repro.serve.engine import compiled_fns
@@ -48,7 +47,8 @@ def main(cells):
         traffic = gen.Traffic(cell.mix, 0, cfg.vocab, conf["slots"], 30.0)
         params = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one),
-            W.shapes(conf), is_leaf=lambda x: isinstance(x, tuple))
+            harness.arch_module(conf).shapes(conf),
+            is_leaf=lambda x: isinstance(x, tuple))
         pool = jax.tree.map(spec, jax.eval_shape(
             lambda: TLM.init_cache(cfg, conf["slots"], conf["max_len"],
                                    jnp.bfloat16)))

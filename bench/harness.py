@@ -3,7 +3,9 @@
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name that ``BENCHMARK.json``
 gives it: ``configs/<config>.json``, ``traffic/<mix>.json`` (whose
-``kind`` names ``gen/<kind>.py``) and ``metrics/<metric>.py``.
+``kind`` names ``gen/<kind>.py``) and ``metrics/<metric>.py``. What depends
+on a model's architecture is one module, ``arch/<name>.py``, named by the
+configuration file's own ``architectures[0]`` (``arch_module``).
 
 The window drives ``repro.serve.Engine`` through ``submit`` and ``step``
 only; the program gets the generated prompts and the benchmark-made
@@ -79,26 +81,47 @@ def load_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> Cell:
                            if _applies(m, name)])
 
 
-def arch_config(conf: dict):
+@functools.lru_cache(maxsize=None)
+def _load_arch(name: str):
+    return load_module(BENCH / "arch" / f"{name}.py")
+
+
+def arch_module(conf: dict):
+    """The architecture module of a configuration file:
+    ``arch/<architectures[0]>.py``, loaded once per process. It provides
+
+    * ``TINY``: the file's size keys at the bench tests' tiny size;
+    * ``CUTS``: file keys that ``reduced`` may cut, each mapped to the
+      program's ``ArchConfig`` field that it sets;
+    * ``fields(conf)``: the ``ArchConfig`` fields that the file fixes;
+    * ``shapes(conf)``: the weight tree's leaf shapes (``weights.py``);
+    * ``cache0(conf)``: the path of layer 0's group in the program's
+      cache pool and the names of its leaves (``live_rows``);
+    * ``projection_weights(conf)``: weights one token multiplies
+      (``ops.py``);
+    * the plain reference, ``sequence_stats`` and ``layer0_cache``
+      (``check.py``), built on ``reference.py``."""
+    return _load_arch(conf["architectures"][0])
+
+
+def arch_config(conf: dict, base=None):
     """The program's ArchConfig for a configuration file, checked against
-    the file's sizes."""
-    import jax.numpy as jnp
+    the file's sizes through its architecture module. Each key in the
+    file's ``reduced`` that the module's ``CUTS`` maps to a field is passed
+    to the registry as an override (a cut in depth); every other field the
+    module names must equal the registry's. ``base`` stands in for the
+    registry's config (the bench tests' tiny size)."""
     from repro.configs import registry
     from repro.nn import layers as L
     from repro.quant.quantize import for_lm
+    module = arch_module(conf)
+    want = module.fields(conf)
+    cuts = {module.CUTS[k]: want[module.CUTS[k]] for k in conf["reduced"]
+            if k in module.CUTS}
     q = conf["quant"]
     cfg = dataclasses.replace(
-        registry.get(conf["registry"]),
-        quant=for_lm(q["backend"], q.get("multiplier", "proposed")))
-    want = {"d_model": conf["hidden_size"], "d_ff": conf["intermediate_size"],
-            "n_layers": conf["num_hidden_layers"],
-            "n_heads": conf["num_attention_heads"],
-            "n_kv_heads": conf["num_key_value_heads"],
-            "vocab": conf["vocab_size"],
-            "dh": conf["hidden_size"] // conf["num_attention_heads"],
-            "rope_theta": conf["rope_theta"],
-            "tied_embeddings": conf["tie_word_embeddings"],
-            "param_dtype": jnp.dtype(conf["torch_dtype"])}
+        base or registry.get(conf["registry"]),
+        quant=for_lm(q["backend"], q.get("multiplier", "proposed")), **cuts)
     got = {k: getattr(cfg, k) for k in want}
     # the program's RMSNorm takes its epsilon from its default argument
     want["rms_norm_eps"] = conf["rms_norm_eps"]
@@ -342,14 +365,17 @@ def drive(engine, traffic, seconds, *, recs, counter, spans, trace_dir,
     return win
 
 
-def live_rows(engine):
+def live_rows(engine, conf):
     """Layer 0's cache as the timed path left it, for every request in a
     slot: its tokens fed so far (the prompt and every output token but the
-    last), their positions, and the keys and values at them (numpy
-    float32, (n, kv heads, head size))."""
-    kv = engine.pool["blocks"][0]["k0_self"]
-    k0, v0 = np.asarray(kv["k"][0]), np.asarray(kv["v"][0])
-    toks, pos, ks, vs = [], [], [], []
+    last), their positions, and ``cache``: each leaf that the architecture
+    module's ``cache0`` names, at those rows (numpy float32, (n, ...))."""
+    path, leaves = arch_module(conf).cache0(conf)
+    group = engine.pool
+    for key in path:
+        group = group[key]
+    layer0 = {n: np.asarray(group[n][0]) for n in leaves}
+    toks, pos, rows = [], [], {n: [] for n in leaves}
     for slot, req in enumerate(engine._slot_req):
         if req is None:
             continue
@@ -357,12 +383,12 @@ def live_rows(engine):
                               np.asarray(req.output[:-1], np.int32)])
         toks.append(seq)
         pos.append(np.arange(len(seq)))
-        ks.append(k0[slot, :len(seq)])
-        vs.append(v0[slot, :len(seq)])
+        for n in leaves:
+            rows[n].append(layer0[n][slot, :len(seq)])
     return {"tokens": np.concatenate(toks).astype(np.int32),
             "pos": np.concatenate(pos).astype(np.int32),
-            "k": np.concatenate(ks).astype(np.float32),
-            "v": np.concatenate(vs).astype(np.float32)}
+            "cache": {n: np.concatenate(rows[n]).astype(np.float32)
+                      for n in leaves}}
 
 
 def serve_setup_requests(engine, traffic):
@@ -541,7 +567,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     pick = check.choose(finished, seed, conf["check"]["served_tokens"],
                         conf["check"]["token_budget"])
     sample = [finished[i] for i in pick]
-    live = live_rows(engine)
+    live = live_rows(engine, conf)
     del engine, params, traffic
     gc.collect()
     t = time.perf_counter()
@@ -549,8 +575,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     numbers, served, split = check.verify(ref_weights, conf, sample, live)
     log(f"check: {len(sample)} requests ({sum(1 for r in sample if r['hit'])}"
         f" on a prefix hit), {served} served tokens; layer 0's cache of "
-        f"{len(live['tokens'])} tokens, keys {split['k']} and values "
-        f"{split['v']} differing; {time.perf_counter() - t:.1f} s")
+        f"{len(live['tokens'])} tokens, share differing by leaf {split}; "
+        f"{time.perf_counter() - t:.1f} s")
     result["correct"] = bool(sample) and all(
         v <= lim for _, v, lim in numbers)
     result["checks"] = {n: {"value": v, "limit": lim}

@@ -3,12 +3,13 @@
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests
 
 They are outside the repository's tier-1 ``testpaths``. ``tiny_cell``
-builds a cell at a reduced size (two layers, d_model 128, vocab 512) with
-traffic scaled to match, so that a whole run, check included, fits in a
-test.
+builds a cell at a reduced size (the sizes its architecture module gives
+as ``TINY``: for a Llama two layers, d_model 128, vocab 512) with traffic
+scaled to match, so that a whole run, check included, fits in a test.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import sys
 import time
@@ -21,9 +22,10 @@ for p in (BENCH.parent / "src", BENCH):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-TINY = dict(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
-            num_attention_heads=4, num_key_value_heads=1, vocab_size=512,
-            slots=4, max_len=256, cache_pages=256, initializer_range=0.2)
+# The tiny cells' engine and initializer (the architecture's sizes are its
+# module's ``TINY``).
+TINY_SERVE = dict(slots=4, max_len=256, cache_pages=256,
+                  initializer_range=0.2)
 
 MIXES = {
     "batch": {"kind": "closed",
@@ -46,24 +48,34 @@ TINY_MAX_GAP = 0.5
 TINY_KV0_MISMATCH = 0.05
 
 
-def tiny_cell(mix: str, config: str):
-    """(Cell, ArchConfig) of ``config`` (``smollm-135m.int8`` or
-    ``.approx``) under the scaled-down ``mix``."""
+def tiny_conf(config):
+    """The configuration file ``config`` (a name under ``configs/``, or the
+    file's content as a dict) at the tiny size."""
+    import harness
+    conf = (harness.load_json(BENCH / "configs" / f"{config}.json")
+            if isinstance(config, str) else copy.deepcopy(config))
+    conf.update(harness.arch_module(conf).TINY, **TINY_SERVE)
+    return conf
+
+
+def tiny_cell(mix: str, config, workload: str = None):
+    """(Cell, ArchConfig) of ``config`` (see ``tiny_conf``) under the
+    scaled-down ``mix``, with the metrics of ``workload`` (by default the
+    ``BENCHMARK.json`` cell of that configuration and mix). The
+    ArchConfig is the registry's reduced one, checked against the file
+    through its architecture module."""
     import jax.numpy as jnp
     import harness
     from repro.configs import registry
-    from repro.quant.quantize import for_lm
-    conf = harness.load_json(BENCH / "configs" / f"{config}.json")
-    conf.update(TINY)
+    conf = tiny_conf(config)
     conf["check"] = {"served_tokens": 64, "token_budget": 2048}
     conf["limits"] = {"max_logit_gap": TINY_MAX_GAP,
                       "kv0_mismatch": TINY_KV0_MISMATCH}
-    arch = dataclasses.replace(registry.reduced(conf["registry"]),
-                               param_dtype=jnp.bfloat16,
-                               quant=for_lm(conf["quant"]["backend"]))
+    arch = harness.arch_config(conf, base=registry.reduced(
+        conf["registry"], param_dtype=jnp.bfloat16))
     spec = harness.load_json(BENCH.parent / "BENCHMARK.json")
-    name = next(w["name"] for w in spec["workloads"]
-                if w["config"] == config and w["traffic"] == mix)
+    name = workload or next(w["name"] for w in spec["workloads"]
+                            if w["config"] == config and w["traffic"] == mix)
     cell = harness.Cell(
         name=name, chips=1, conf=conf, mix=MIXES[mix],
         end_to_end=[m for m in spec["end_to_end"]
@@ -79,8 +91,9 @@ def run_tiny(tmp_path):
     import harness
 
     def run(mix, config="smollm-135m.int8", *, seed=2**31 + 7, seconds=3.0,
-            trace=False, fault=None, control=False, backend=None):
-        cell, arch = tiny_cell(mix, config)
+            trace=False, fault=None, control=False, backend=None,
+            workload=None):
+        cell, arch = tiny_cell(mix, config, workload)
         if backend is not None:
             from repro.quant.quantize import for_lm
             arch = dataclasses.replace(arch, quant=for_lm(backend))

@@ -11,7 +11,8 @@ def test_int8_ops_hand_count_smollm():
     layer = 576 * 576 + 2 * 576 * 192 + 576 * 576 + 3 * 576 * 1536
     assert layer == 3_538_944
     head = 576 * 49152
-    assert ops.projection_weights(conf) == 30 * layer + head == 134_479_872
+    assert harness.arch_module(conf).projection_weights(conf) == \
+        30 * layer + head == 134_479_872
     assert ops.int8_ops_per_token(conf) == 2 * 134_479_872
 
 

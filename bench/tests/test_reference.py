@@ -1,4 +1,5 @@
-"""The plain reference: its multiplier and integer core."""
+"""The plain reference: its multiplier, integer core and W8A8 product, and
+a Llama's teacher-forced pass."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -51,15 +52,15 @@ def test_qmm_int4_codes_are_coarser():
 def test_sequence_stats_targets_and_argmax():
     import harness
     import weights as W
-    from conftest import BENCH, TINY
-    conf = harness.load_json(BENCH / "configs" / "smollm-135m.int8.json")
-    conf.update(TINY)
+    from conftest import tiny_conf
+    conf = tiny_conf("smollm-135m.int8")
+    module = harness.arch_module(conf)
     w = W.make(conf, 3)
     toks = np.arange(1, 20, dtype=np.int32)
-    best, at, arg = reference.sequence_stats(w, conf, toks)
+    best, at, arg = module.sequence_stats(w, conf, toks)
     assert best.shape == at[:, 0].shape == arg.shape == (18,)
     assert (at[:, 0] <= best).all()
-    b2, at2, _ = reference.sequence_stats(w, conf, toks, arg[:, None],
-                                          vocab_chunk=100)
+    b2, at2, _ = module.sequence_stats(w, conf, toks, arg[:, None],
+                                       vocab_chunk=100)
     np.testing.assert_array_equal(b2, best)
     np.testing.assert_array_equal(at2[:, 0], best)
